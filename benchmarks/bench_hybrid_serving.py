@@ -92,8 +92,8 @@ def run_arch(arch: str, quick: bool) -> None:
     assert served and len(served) == len(wl), "hybrid trace dropped requests"
     assert rt.slots.num_active == 0, "slots leaked"
     assert rt.pool.in_use == 0, "KV blocks leaked"
-    assert rt.decode_compiles() in (1, -1), "decode step re-jitted"
-    assert rt.prefill_compiles() in (1, -1), "chunked prefill re-jitted"
+    assert rt.decode_compiles() == 1, "decode step re-jitted"
+    assert rt.prefill_compiles() == 1, "chunked prefill re-jitted"
     # accounting sanity: the docs-table number equals the MEASURED nbytes
     # of one slot's rows in the live cache (independent of the formula)
     ext = make_state_extract_fn(cfg)(rt.cache, 0)

@@ -31,7 +31,8 @@ import numpy as np
 
 from repro.configs import get_smoke
 from repro.core.lora import (backbone_param_count, combine_lora,
-                             lora_param_count, partition_lora)
+                             lora_param_count, partition_lora,
+                             random_adapter)
 from repro.models import transformer as tf
 from repro.serverless.traces import TraceSpec, make_workload
 from repro.serving import (AdapterRegistry, CompileGuard, ContinuousRuntime,
@@ -39,22 +40,6 @@ from repro.serving import (AdapterRegistry, CompileGuard, ContinuousRuntime,
 
 PROMPT_LEN = 16
 SLO = 8.0
-
-
-def _rand_adapter(params, seed: int):
-    """Single-adapter LoRA tree with random a AND b (init leaves b = 0 —
-    a zero delta would make the bitwise comparison vacuous)."""
-    _, bank = partition_lora(params)
-    one = jax.tree_util.tree_map(
-        lambda x: None if x is None else x[..., 0, :, :],
-        bank, is_leaf=lambda x: x is None)
-    leaves, treedef = jax.tree_util.tree_flatten(
-        one, is_leaf=lambda x: x is None)
-    ks = jax.random.split(jax.random.PRNGKey(seed), max(len(leaves), 1))
-    new = [None if lf is None else
-           jax.random.normal(k, lf.shape, lf.dtype) * 0.05
-           for lf, k in zip(leaves, ks)]
-    return jax.tree_util.tree_unflatten(treedef, new)
 
 
 def _single_adapter_params(params, slot: int):
@@ -106,7 +91,7 @@ def run(adapters: int = 3, rate: float = 60.0, duration: float = 0.6,
     reg = AdapterRegistry(rt)
     names = [f"fn{a}" for a in range(adapters)]
     for i, name in enumerate(names):
-        reg.load(name, _rand_adapter(params, 100 + i))
+        reg.load(name, random_adapter(params, 100 + i))
     print(f"bank: {adapters} adapters loaded into {reg.capacity} slots "
           f"({', '.join(reg.names())})")
 
@@ -132,7 +117,7 @@ def run(adapters: int = 3, rate: float = 60.0, duration: float = 0.6,
         # slot for a brand-new adapter — zero recompiles
         reg.unload(names[0])
         churn_name = "fn_new"
-        slot = reg.load(churn_name, _rand_adapter(params, 999))
+        slot = reg.load(churn_name, random_adapter(params, 999))
         print(f"churn: unloaded {names[0]}, loaded {churn_name} into "
               f"recycled slot {slot}")
         fn_map2 = {n: n for n in names[1:] + [churn_name]}

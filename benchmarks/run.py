@@ -1,5 +1,7 @@
 """Benchmark driver — one module per paper table/figure.  Emits
 ``name,us_per_call,derived`` CSV and writes results/benchmarks.csv.
+A suite that raises gets an ERROR row, the others still run, and the
+driver then exits non-zero.
 
 Roofline rows (deliverable g) are appended when dry-run artifacts exist
 (run ``python -m repro.launch.dryrun --all`` first).
@@ -10,6 +12,7 @@ import argparse
 import os
 import sys
 import time
+import traceback
 
 
 def main() -> None:
@@ -45,13 +48,16 @@ def main() -> None:
     ]
 
     all_rows = ["name,us_per_call,derived"]
+    failed = []
     for name, fn in suites:
         if args.only and args.only not in name:
             continue
         t0 = time.monotonic()
         try:
             rows = fn()
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — record, run the rest, fail
+            traceback.print_exc()
+            failed.append(name)
             rows = [f"{name}/ERROR,0,{type(e).__name__}:{e}"]
         dt = time.monotonic() - t0
         print(f"# {name} ({dt:.1f}s)", file=sys.stderr)
@@ -59,25 +65,24 @@ def main() -> None:
             print(r)
             all_rows.append(r)
 
-    # roofline rows from dry-run artifacts, if present
-    try:
-        from benchmarks.roofline import roofline_table
-        rows = roofline_table()
-        for r in rows:
-            line = (f"roofline/{r['arch']}/{r['shape']},0,"
-                    f"compute_s={r['compute_s']:.5f} "
-                    f"memory_s={r['memory_s']:.5f} "
-                    f"collective_s={r['collective_s']:.5f} "
-                    f"dominant={r['dominant']} useful={r['useful_ratio']:.2f}")
-            print(line)
-            all_rows.append(line)
-    except Exception as e:  # noqa: BLE001
-        print(f"# roofline skipped: {e}", file=sys.stderr)
+    # roofline rows from dry-run artifacts, if present (none -> no rows)
+    from benchmarks.roofline import roofline_table
+    for r in roofline_table():
+        line = (f"roofline/{r['arch']}/{r['shape']},0,"
+                f"compute_s={r['compute_s']:.5f} "
+                f"memory_s={r['memory_s']:.5f} "
+                f"collective_s={r['collective_s']:.5f} "
+                f"dominant={r['dominant']} useful={r['useful_ratio']:.2f}")
+        print(line)
+        all_rows.append(line)
 
     out = os.path.join(os.path.dirname(__file__), "..", "results")
     os.makedirs(out, exist_ok=True)
     with open(os.path.join(out, "benchmarks.csv"), "w") as f:
         f.write("\n".join(all_rows) + "\n")
+    if failed:
+        raise SystemExit(f"{len(failed)} benchmark suite(s) raised: "
+                         + ", ".join(failed))
 
 
 if __name__ == "__main__":

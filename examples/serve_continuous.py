@@ -14,29 +14,14 @@ import argparse
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke
-from repro.core.lora import partition_lora
+from repro.core.lora import random_adapter
 from repro.models import transformer as tf
 from repro.serverless.traces import TraceSpec, make_workload
 from repro.serving import (AdapterRegistry, ContinuousRuntime,
                            SamplingParams, ServingConfig, Telemetry,
                            replay_trace, write_metrics_json)
-
-
-def _rand_adapter(params, seed):
-    """Random a AND b for one adapter (init leaves b = 0, i.e. a zero
-    delta — fine for shapes, useless for a multi-adapter demo)."""
-    _, bank = partition_lora(params)
-    one = jax.tree_util.tree_map(
-        lambda x: None if x is None else x[..., 0, :, :],
-        bank, is_leaf=lambda x: x is None)
-    leaves, treedef = jax.tree_util.tree_flatten(
-        one, is_leaf=lambda x: x is None)
-    ks = jax.random.split(jax.random.PRNGKey(seed), max(len(leaves), 1))
-    new = [None if lf is None else
-           jax.random.normal(k, lf.shape, lf.dtype) * 0.05
-           for lf, k in zip(leaves, ks)]
-    return jax.tree_util.tree_unflatten(treedef, new)
 
 
 def main():
@@ -90,6 +75,7 @@ def main():
     if args.shared_prefix >= args.prompt_len:
         raise SystemExit("--shared-prefix must be < --prompt-len")
 
+    print(f"compile cache: {enable_compile_cache()}")
     cfg = get_smoke(args.arch).with_(name="serve-continuous",
                                      dtype="float32")
     params = tf.init_params(jax.random.PRNGKey(0), cfg,
@@ -100,7 +86,7 @@ def main():
     rt = ContinuousRuntime(cfg, params, scfg)
     reg = AdapterRegistry(rt)
     for a in range(args.adapters):
-        reg.load(f"fn{a}", _rand_adapter(params, 100 + a))
+        reg.load(f"fn{a}", random_adapter(params, 100 + a))
     print(f"registry: {args.adapters} named adapters live in a "
           f"{reg.capacity}-slot bank, one backbone resident "
           f"({', '.join(reg.names())})")

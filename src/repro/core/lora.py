@@ -129,6 +129,24 @@ def take_adapter(adapters: Params, i: int) -> Params:
         is_leaf=lambda x: x is None)
 
 
+def random_adapter(params: Params, seed: int) -> Params:
+    """One adapter for the bank in ``params`` (its leaves without the
+    adapter axis) with random ``a`` AND ``b`` (std 0.05) — ``init_params``
+    leaves b = 0, a zero delta that would make multi-adapter outputs
+    identical."""
+    _, bank = partition_lora(params)
+    one = jax.tree_util.tree_map(
+        lambda x: None if x is None else x[..., 0, :, :],
+        bank, is_leaf=lambda x: x is None)
+    leaves, treedef = jax.tree_util.tree_flatten(
+        one, is_leaf=lambda x: x is None)
+    ks = jax.random.split(jax.random.PRNGKey(seed), max(len(leaves), 1))
+    new = [None if lf is None else
+           jax.random.normal(k, lf.shape, lf.dtype) * 0.05
+           for lf, k in zip(leaves, ks)]
+    return jax.tree_util.tree_unflatten(treedef, new)
+
+
 def bank_size(adapters: Params) -> int:
     """Capacity N of a stacked adapter bank (size of the adapter axis)."""
     for leaf in jax.tree_util.tree_leaves(adapters):

@@ -3,19 +3,6 @@
 # kernel. Leave this package empty if the paper has none.
 
 
-def tpu_compiler_params():
-    """The Pallas-TPU compiler-params class under its jax-version-dependent
-    name (TPUCompilerParams on jax<=0.4.x, CompilerParams later)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        raise ImportError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams "
-            "nor TPUCompilerParams; unsupported jax version")
-    return cls
-
-
 def largest_divisor_block(n: int, target: int) -> int:
     """Largest block size <= ``target`` that divides ``n`` exactly — the
     shared tail-safe tiling rule (min(target, n) alone crashes on

@@ -24,9 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import largest_divisor_block, tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()
+from repro.kernels import largest_divisor_block
 
 NEG_INF = -1e30
 
@@ -110,7 +108,7 @@ def decode_attention(q, k, v, slot_pos, pos, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos.reshape(1), q, k, v, slot_pos)

@@ -23,9 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()
 
 NEG_INF = -1e30
 
@@ -123,7 +120,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((q_block,), jnp.float32),
             pltpu.VMEM((q_block,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

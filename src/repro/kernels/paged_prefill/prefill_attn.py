@@ -42,9 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import largest_divisor_block, tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()
+from repro.kernels import largest_divisor_block
 
 NEG_INF = -1e30
 
@@ -155,7 +153,7 @@ def paged_prefill_attention(q, kp, vp, block_tbl, start, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, C, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary")),
         interpret=interpret,

@@ -20,9 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()
+from repro.kernels import largest_divisor_block
 
 
 def _sgmv_kernel(idx_ref, x_ref, a_ref, b_ref, y_ref, acc_ref, *,
@@ -66,9 +64,8 @@ def sgmv(x, a, b, block_idx, *, row_block: int = 8,
     N, _, r = a.shape
     O = b.shape[-1]
     assert R % row_block == 0, (R, row_block)
-    d_block = min(d_block, D)
-    o_block = min(o_block, O)
-    assert D % d_block == 0 and O % o_block == 0, (D, d_block, O, o_block)
+    d_block = largest_divisor_block(D, d_block)
+    o_block = largest_divisor_block(O, o_block)
     n_rows, n_o, n_d = R // row_block, O // o_block, D // d_block
 
     grid = (n_rows, n_o, n_d)
@@ -91,7 +88,7 @@ def sgmv(x, a, b, block_idx, *, row_block: int = 8,
             scratch_shapes=[pltpu.VMEM((row_block, r), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((R, O), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_idx, x, a, b)

@@ -17,13 +17,6 @@ from repro.models.layers import _normal
 
 Params = Dict[str, Any]
 
-# shard_map moved to jax top level (and check_rep -> check_vma) in newer jax
-if hasattr(jax, "shard_map"):
-    _shard_map, _CHECK_KW = jax.shard_map, "check_vma"
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 
 def moe_init(key, cfg: ModelConfig, dtype) -> Params:
     import math
@@ -186,10 +179,10 @@ def _apply_moe_shard_map(p: Params, cfg: ModelConfig, x):
         aux = {k: jax.lax.pmean(v, da) for k, v in aux.items()}
         return out.reshape(b, t, d), aux
 
-    out, aux = _shard_map(local, mesh=mesh,
-                          in_specs=(p_specs, x_spec),
-                          out_specs=(x_spec, P()),
-                          **{_CHECK_KW: False})(p, x)
+    out, aux = jax.shard_map(local, mesh=mesh,
+                             in_specs=(p_specs, x_spec),
+                             out_specs=(x_spec, P()),
+                             check_vma=False)(p, x)
     return out.astype(x.dtype), aux
 
 

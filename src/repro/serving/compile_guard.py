@@ -11,17 +11,13 @@ happen.  Any test or bench wraps its serving code in::
 and fails loudly — ``CompileBudgetExceeded`` — if a watched jitted
 function compiled more often than its budget while the guard was
 active.  A silent re-jit mid-serving is a cold start by another name
-(it blows every TPOT SLO the paper's scheduling is built around), and
-before this guard it was only caught by scattered
-``decode_compiles() in (1, -1)`` assertions.
+(it blows every TPOT SLO the paper's scheduling is built around).
 
 Two measurement channels:
 
 * **per-function** (``max_compiles``): named jitted callables
   registered via :meth:`watch` / :meth:`attach`; compile counts come
-  from the jit cache-size probe (``fn._cache_size()``).  When the
-  probe is unavailable on a jax version, that watch is skipped — same
-  contract as the runtime's ``decode_compiles() == -1``.
+  from the jit cache-size probe (``fn._cache_size()``).
 * **process-wide** (``max_total``): every XLA backend compile,
   observed via ``jax.monitoring``'s
   ``/jax/core/compile/backend_compile_duration`` event.  This counts
@@ -48,14 +44,6 @@ class CompileBudgetExceeded(AssertionError):
     """A watched function compiled more often than its budget."""
 
 
-def _cache_size(fn: Any) -> Optional[int]:
-    """Jit cache-size probe; None when this jax build lacks it."""
-    try:
-        return int(fn._cache_size())
-    except AttributeError:
-        return None
-
-
 class CompileGuard:
     """Context manager asserting compile budgets over its body.
 
@@ -77,7 +65,7 @@ class CompileGuard:
         self.max_total = max_total
         self._runtime = runtime
         self._watched: Dict[str, Any] = {}
-        self._baseline: Dict[str, Optional[int]] = {}
+        self._baseline: Dict[str, int] = {}
         self.backend_compiles = 0
         self.backend_compile_seconds = 0.0
         self._listener: Optional[Callable] = None
@@ -88,7 +76,7 @@ class CompileGuard:
         """Watch a jitted callable; baseline is its current cache size
         (so only compiles that happen *inside* the guard count)."""
         self._watched[name] = fn
-        self._baseline[name] = _cache_size(fn)
+        self._baseline[name] = fn._cache_size()
         return self
 
     def attach(self, runtime: Any) -> "CompileGuard":
@@ -97,14 +85,9 @@ class CompileGuard:
                    .watch("prefill", runtime._prefill)
 
     # -- measurement ------------------------------------------------
-    def compiles(self, name: str) -> Optional[int]:
-        """New compiles of ``name`` since it was watched (None when
-        the probe is unavailable)."""
-        base = self._baseline.get(name)
-        now = _cache_size(self._watched[name])
-        if base is None or now is None:
-            return None
-        return now - base
+    def compiles(self, name: str) -> int:
+        """New compiles of ``name`` since it was watched."""
+        return self._watched[name]._cache_size() - self._baseline[name]
 
     def report(self) -> Dict[str, Any]:
         out: Dict[str, Any] = {
@@ -123,7 +106,7 @@ class CompileGuard:
             if name not in self._watched:
                 continue
             n = self.compiles(name)
-            if n is not None and n > budget:
+            if n > budget:
                 raise CompileBudgetExceeded(
                     f"'{name}' compiled {n}x inside CompileGuard "
                     f"(budget {budget}) — a re-jit mid-serving is a "
@@ -146,23 +129,14 @@ class CompileGuard:
         if self._runtime is not None:
             self.attach(self._runtime)
         self._listener = self._on_event
-        try:
-            jax.monitoring.register_event_duration_secs_listener(
-                self._listener)
-        except AttributeError:  # jax without the monitoring API
-            self._listener = None
+        jax.monitoring.register_event_duration_secs_listener(self._listener)
         self._active = True
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self._active = False
         if self._listener is not None:
-            try:
-                from jax._src import monitoring as _mon
-                _mon._unregister_event_duration_listener_by_callback(
-                    self._listener)
-            except (ImportError, AttributeError, ValueError):
-                pass  # best effort: stale listeners only no-op
+            jax.monitoring.unregister_event_duration_listener(self._listener)
             self._listener = None
         if exc_type is None:
             self.check()

@@ -857,11 +857,9 @@ class ContinuousRuntime:
                 jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
                 jnp.asarray(seed))
             if r in final_rounds:
-                if hasattr(first, "copy_to_host_async"):
-                    # start the D2H transfer now so it overlaps the
-                    # remaining prefill rounds instead of stalling at
-                    # the sync below
-                    first.copy_to_host_async()
+                # start the D2H transfer now so it overlaps the remaining
+                # prefill rounds instead of stalling at the sync below
+                first.copy_to_host_async()
                 toks_by_round[r] = first
             self.stats["prefill_chunks"] += 1
         # One whole-batch transfer per final round, then index on host.
@@ -1459,45 +1457,54 @@ class ContinuousRuntime:
         every metrics snapshot carries the Eq. 2 profile the admission
         scheduler was seeded with, instead of the dict being dropped
         after ``replay_trace`` wires the scheduler."""
-        scfg, timings = self.scfg, {}
-        C, G = scfg.prefill_chunk, scfg.prefill_rows
-        ids = jnp.full((G, C // scfg.block_size), GARBAGE_BLOCK, jnp.int32)
-        tbl = jnp.full((G, scfg.max_blocks_per_slot), -1, jnp.int32)
-        zeros = jnp.zeros((G,), jnp.int32)
-        # warmup rows write the garbage state row only (real slot rows stay
-        # untouched, same as the garbage block for K/V)
-        g_pre = jnp.full((G,), self.garbage_state_row, jnp.int32)
-        g_dec = jnp.full((scfg.num_slots,), self.garbage_state_row, jnp.int32)
-        # warmup rows sample in greedy mode (temp 0 / k off / p off) —
-        # the sampling vectors are data, so this compiles the ONE shape
-        # every later mode mix reuses
-        B = scfg.num_slots
-        for rep in range(2):
-            t0 = self._timer()
-            first, self.cache = self._prefill(
-                self.params, jnp.zeros((G, C), jnp.int32), zeros, zeros,
-                zeros, self.cache, ids, tbl, g_pre,
-                jnp.zeros((G,), jnp.float32), jnp.zeros((G,), jnp.int32),
-                jnp.ones((G,), jnp.float32), jnp.zeros((G,), jnp.int32))
-            np.asarray(first)
-            timings["prefill_chunk_s"] = self._timer() - t0
-        for rep in range(2):
-            t0 = self._timer()
-            toks, self.cache = self._decode(
-                self.params, jnp.asarray(self.slots.tokens), self.cache,
-                jnp.asarray(self.slots.pos),
-                jnp.asarray(self.slots.block_tbl),
-                jnp.asarray(self.slots.adapter), g_dec,
-                jnp.zeros((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
-                jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
-                jnp.zeros((B,), jnp.int32))
-            np.asarray(toks)
-            timings["decode_chunk_s"] = self._timer() - t0
+        timings = {}
+        for kind, fn in (("prefill", self._prefill),
+                         ("decode", self._decode)):
+            for rep in range(2):
+                t0 = self._timer()
+                out, self.cache = fn(*self._warmup_args(kind))
+                np.asarray(out)
+                timings[f"{kind}_chunk_s"] = self._timer() - t0
         for key, val in timings.items():
             self.metrics.gauge(
                 f"warmup_{key}", "steady-state step latency measured at "
                 "warmup (the admission scheduler's Eq. 2 seed)").set(val)
         return timings
+
+    def _warmup_args(self, kind: str) -> Tuple[Any, ...]:
+        """Arguments of one ``kind`` ("prefill" / "decode") dispatch at
+        the serving shape.  Warmup rows write only the garbage block and
+        the garbage state row, and sample in greedy mode (temp 0 / k off /
+        p off): the sampling vectors are data, so this is the ONE shape
+        every later mode mix reuses."""
+        scfg = self.scfg
+        if kind == "prefill":
+            G, C = scfg.prefill_rows, scfg.prefill_chunk
+            zeros = jnp.zeros((G,), jnp.int32)
+            return (self.params, jnp.zeros((G, C), jnp.int32), zeros, zeros,
+                    zeros, self.cache,
+                    jnp.full((G, C // scfg.block_size), GARBAGE_BLOCK,
+                             jnp.int32),
+                    jnp.full((G, scfg.max_blocks_per_slot), -1, jnp.int32),
+                    jnp.full((G,), self.garbage_state_row, jnp.int32),
+                    jnp.zeros((G,), jnp.float32), zeros,
+                    jnp.ones((G,), jnp.float32), zeros)
+        B = scfg.num_slots
+        zeros = jnp.zeros((B,), jnp.int32)
+        return (self.params, jnp.asarray(self.slots.tokens), self.cache,
+                jnp.asarray(self.slots.pos),
+                jnp.asarray(self.slots.block_tbl),
+                jnp.asarray(self.slots.adapter),
+                jnp.full((B,), self.garbage_state_row, jnp.int32),
+                jnp.zeros((B,), jnp.float32), zeros,
+                jnp.ones((B,), jnp.float32), zeros, zeros)
+
+    def lower_dispatch(self, kind: str) -> jax.stages.Lowered:
+        """The ``kind`` ("prefill" / "decode") program lowered at its one
+        serving shape, without running it: ``.as_text()`` names the
+        kernels it calls (``tpu_custom_call`` for a Pallas kernel)."""
+        fn = {"prefill": self._prefill, "decode": self._decode}[kind]
+        return fn.lower(*self._warmup_args(kind))
 
     def _sample_gauges(self) -> None:
         """Sample the occupancy gauges at a scheduling boundary (end of
@@ -1535,13 +1542,11 @@ class ContinuousRuntime:
         ``BENCH_serving.json``) call this once after a replay."""
         self._sample_gauges()
         self.metrics.gauge(
-            "decode_compiles", "decode-step compile count (must be 1; -1 "
-            "when the jit cache probe is unavailable)").set(
-            self.decode_compiles())
+            "decode_compiles", "decode-step compile count (must be 1)"
+        ).set(self.decode_compiles())
         self.metrics.gauge(
-            "prefill_compiles", "chunked-prefill compile count (must be "
-            "1; -1 when the probe is unavailable)").set(
-            self.prefill_compiles())
+            "prefill_compiles", "chunked-prefill compile count (must be 1)"
+        ).set(self.prefill_compiles())
         snap = self.metrics.snapshot()
         snap["host_bubble_fraction"] = self.host_bubble_fraction()
         snap["dispatches"] = len(self._dispatch_windows)
@@ -1553,16 +1558,10 @@ class ContinuousRuntime:
     def decode_compiles(self) -> int:
         """Compile-count probe for the decode step (must be 1 after warmup;
         re-jit mid-serving would blow every TPOT SLO)."""
-        try:
-            return int(self._decode._cache_size())
-        except AttributeError:              # older/newer jax without probe
-            return -1
+        return int(self._decode._cache_size())
 
     def prefill_compiles(self) -> int:
         """Compile-count probe for the chunked prefill step (must be 1
         after warmup across EVERY prompt length — the bucketed path paid
         one compile per bucket, all of them at cold-start warmup)."""
-        try:
-            return int(self._prefill._cache_size())
-        except AttributeError:              # older/newer jax without probe
-            return -1
+        return int(self._prefill._cache_size())

@@ -9,17 +9,11 @@ import jax.numpy as jnp
 import pytest
 
 from repro.serving import CompileBudgetExceeded, CompileGuard
-from repro.serving.compile_guard import _cache_size
-
-
-def _probe_or_skip(fn):
-    if _cache_size(fn) is None:
-        pytest.skip("jit cache-size probe unavailable on this jax")
+from repro.serving.compile_guard import _COMPILE_EVENT
 
 
 def test_shape_unstable_dispatch_fails():
     f = jax.jit(lambda x: x * 2.0)
-    _probe_or_skip(f)
     with pytest.raises(CompileBudgetExceeded, match="compiled 2x"):
         with CompileGuard({"f": 1}) as guard:
             guard.watch("f", f)
@@ -29,7 +23,6 @@ def test_shape_unstable_dispatch_fails():
 
 def test_fixed_shape_replay_passes():
     f = jax.jit(lambda x: x + 1.0)
-    _probe_or_skip(f)
     with CompileGuard({"f": 1}) as guard:
         guard.watch("f", f)
         for _ in range(4):
@@ -41,7 +34,6 @@ def test_baseline_excludes_prior_compiles():
     """Compiles before the watch (cold-start warmup outside the guard)
     must not count against the budget."""
     f = jax.jit(lambda x: x - 1.0)
-    _probe_or_skip(f)
     f(jnp.zeros((4,)))              # pre-guard warmup
     with CompileGuard({"f": 0}) as guard:
         guard.watch("f", f)
@@ -56,7 +48,6 @@ def test_attach_watches_runtime_dispatches():
             self._prefill = jax.jit(lambda x: x * 3)
 
     rt = FakeRuntime()
-    _probe_or_skip(rt._decode)
     with CompileGuard({"decode": 1, "prefill": 1}, runtime=rt) as guard:
         rt._decode(jnp.zeros((2,)))
         rt._prefill(jnp.zeros((2,)))
@@ -70,7 +61,6 @@ def test_body_exception_not_masked():
     """A blown budget must not shadow the body's own failure — check()
     only runs on a clean exit."""
     f = jax.jit(lambda x: x / 2.0)
-    _probe_or_skip(f)
     with pytest.raises(RuntimeError, match="body failed"):
         with CompileGuard({"f": 0}) as guard:
             guard.watch("f", f)
@@ -80,7 +70,6 @@ def test_body_exception_not_masked():
 
 def test_unbudgeted_watch_reports_without_enforcing():
     f = jax.jit(lambda x: x * 5.0)
-    _probe_or_skip(f)
     with CompileGuard() as guard:   # no budgets at all
         guard.watch("f", f)
         f(jnp.zeros((2,)))
@@ -90,9 +79,25 @@ def test_unbudgeted_watch_reports_without_enforcing():
 
 
 def test_max_total_counts_backend_compiles():
-    if not hasattr(jax.monitoring,
-                   "register_event_duration_secs_listener"):
-        pytest.skip("jax.monitoring listener API unavailable")
     with pytest.raises(CompileBudgetExceeded, match="backend compiles"):
         with CompileGuard(max_total=0):
             jax.jit(lambda x: x @ x)(jnp.zeros((3, 3)))
+
+
+def test_exit_unregisters_listener():
+    """After ``__exit__`` the guard's listener is gone from jax.monitoring:
+    a later compile never reaches its callback, and guards do not pile up
+    listeners for the life of the process."""
+    from jax._src import monitoring
+    before = len(monitoring.get_event_duration_listeners())
+    seen = []
+    guard = CompileGuard()
+    guard._on_event = lambda event, duration, **kw: seen.append(event)
+    with guard:
+        assert len(monitoring.get_event_duration_listeners()) == before + 1
+        jax.jit(lambda x: x * 7.0)(jnp.zeros((5,)))
+    assert _COMPILE_EVENT in seen
+    assert len(monitoring.get_event_duration_listeners()) == before
+    seen.clear()
+    jax.jit(lambda x: x * 11.0)(jnp.zeros((9,)))    # a fresh compile
+    assert seen == []

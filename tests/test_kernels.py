@@ -19,6 +19,9 @@ from repro.kernels.sgmv.ref import sgmv_ref
     (24, 128, 16, 128, 4, 8),
     (8, 256, 4, 64, 1, 4),
     (32, 512, 32, 256, 6, 16),
+    # phi3-medium width, not a power of two: D and O split into 1280-wide
+    # tiles (the largest divisor of 5120 within the 2048 target)
+    (16, 5120, 16, 2560, 3, 8),
 ])
 def test_sgmv_matches_oracle(R, D, r, O, N, rb, dtype):
     ks = jax.random.split(jax.random.PRNGKey(R + N), 4)

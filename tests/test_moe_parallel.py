@@ -47,6 +47,7 @@ SCRIPT = textwrap.dedent("""
 def test_shard_map_moe_exact_in_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"     # never reach for a chip the parent holds
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                        capture_output=True, text=True, timeout=420,

@@ -9,12 +9,11 @@ than a cold admission of the same prompt.
 """
 import dataclasses
 
-import jax
 import numpy as np
 import pytest
 
 from repro.checkpoint.store import load_checkpoint, save_checkpoint
-from repro.core.lora import partition_lora
+from repro.core.lora import random_adapter
 from repro.serverless.batching import Request
 from repro.serverless.traces import TraceSpec, make_workload
 from repro.serving import (AdapterRegistry, ArtifactFault,
@@ -38,20 +37,6 @@ def _workload(duration=3.0, seed=5, output_len=8, rate=1.5, fns=3):
                        output_len=output_len, slo_ttft=1e9)
              for i in range(fns)]
     return make_workload(specs, seed=seed), {f"fn{i}": i for i in range(fns)}
-
-
-def _rand_adapter(params, seed):
-    _, bank = partition_lora(params)
-    one = jax.tree_util.tree_map(
-        lambda x: None if x is None else x[..., 0, :, :],
-        bank, is_leaf=lambda x: x is None)
-    leaves, treedef = jax.tree_util.tree_flatten(
-        one, is_leaf=lambda x: x is None)
-    ks = jax.random.split(jax.random.PRNGKey(seed), max(len(leaves), 1))
-    new = [None if lf is None else
-           jax.random.normal(k, lf.shape, lf.dtype) * 0.05
-           for lf, k in zip(leaves, ks)]
-    return jax.tree_util.tree_unflatten(treedef, new)
 
 
 # ------------------------------------------------------ retry primitive
@@ -365,7 +350,7 @@ def test_adapter_load_retries_then_rolls_back(llama_model):
     cfg, params = llama_model
     rt = _mk_rt(cfg, params)          # robust.artifact_retries = 2
     reg = AdapterRegistry(rt, names=["a0"])
-    tree = _rand_adapter(params, 1)
+    tree = random_adapter(params, 1)
 
     rt.faults = FaultPlan(artifact_faults=[
         ArtifactFault("adapter", name="flaky", fails=2)])

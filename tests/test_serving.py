@@ -265,8 +265,7 @@ def test_replay_trace_end_to_end(llama_model):
     assert len(wl) > 10
     # CompileGuard raises CompileBudgetExceeded on __exit__ if either
     # jitted step compiled more than once across the whole replay
-    # (warmup included) — the guard form of the retired
-    # ``decode_compiles() in (1, -1)`` asserts.
+    # (warmup included).
     with CompileGuard({"decode": 1, "prefill": 1}, runtime=rt):
         res, events = replay_trace(rt, wl, {f"fn{i}": i for i in range(3)},
                                    collect_events=True)
@@ -538,3 +537,18 @@ def test_pool_exhaustion_progress(llama_model):
     assert rt.slots.num_active == 0
     done = [r for r in res.requests if r.done >= 0]
     assert done, "no request ever completed"
+
+
+def test_lower_dispatch_is_the_served_program(llama_model):
+    """``lower_dispatch`` lowers decode and prefill at the one serving
+    shape without compiling or running them: warmup afterwards still
+    compiles each exactly once.  Off the TPU neither program calls a
+    Pallas kernel (the jnp walk and the SGMV reference serve there)."""
+    cfg, params = llama_model
+    rt = make_runtime(cfg, params)
+    for kind in ("decode", "prefill"):
+        text = rt.lower_dispatch(kind).as_text()
+        assert "tpu_custom_call" not in text
+    assert rt.decode_compiles() == 0 and rt.prefill_compiles() == 0
+    rt.warmup()
+    assert rt.decode_compiles() == 1 and rt.prefill_compiles() == 1
