@@ -17,14 +17,8 @@ the decode step compiles exactly once after warmup — enforced by running
 the whole replay under ``CompileGuard(max_compiles={"decode": 1,
 "prefill": 1})`` (docs/static-analysis.md).
 
-Also reports the **host-bubble fraction** — host-plan wall time / total
-wall time between the first admit dispatch and the last finish dispatch
-(the share of the serving window the device spent idle while the host
-planned admission, block tables, and numpy mirrors).  This is the metric
-the ROADMAP's async-overlap item is gated on: the overlap win must be
-measured against this baseline, not assumed.  The headline numbers plus
-the runtime's full metrics snapshot are recorded under
-``results/BENCH_serving.json`` (``common.record_bench``).
+The headline numbers plus the runtime's full metrics snapshot are
+recorded under ``results/BENCH_serving.json`` (``common.record_bench``).
 
 Run: PYTHONPATH=src python -m benchmarks.bench_continuous
 """
@@ -236,16 +230,11 @@ def run(adapters: int = 3, rate: float = 200.0, duration: float = 1.0,
         max(rows["continuous-real"]["tok_per_s"], 1e-9)
     mode_counters = {k: v for k, v in rt_s.stats.items()
                      if k.startswith("tokens_mode_") or k == "sampled_tokens"}
-    bubble = rt.host_bubble_fraction()
-    rows["continuous-real"]["host_bubble_frac"] = bubble
     print(f"\ncontinuous/static throughput: {speedup:.2f}x")
     print(f"sampled/greedy throughput parity: {parity:.2f}x "
           f"(mixed temperature/top-k/top-p vs all-greedy, same trace; "
           f"compile guard: {guard_s.report()})")
     print(f"sampling mode counters: {mode_counters}")
-    print(f"host-bubble fraction: {bubble:.3f} "
-          f"(host-plan wall time / wall time between first admit and "
-          f"last finish — the async-overlap headroom)")
     greport = guard.report()
     print(f"compile guard: {greport}")
     syncs = rt.stats["admit_syncs"]
@@ -253,7 +242,6 @@ def run(adapters: int = 3, rate: float = 200.0, duration: float = 1.0,
     print(f"admission syncs: {syncs} whole-batch logit transfers "
           f"(before: the per-item np.asarray loop paid "
           f"{served_cont} — one device sync per admitted prompt)")
-    assert 0.0 <= bubble <= 1.0, f"host-bubble fraction {bubble} not in [0,1]"
     # throughput comparison is only meaningful under backlog: when both
     # systems drain arrivals in real time, tok/s is arrival-limited on both
     # sides and the ratio is measurement noise around 1.0
@@ -276,7 +264,6 @@ def run(adapters: int = 3, rate: float = 200.0, duration: float = 1.0,
         "sampling_parity_vs_greedy": parity,
         "sampling_mode_counters": mode_counters,
         "sampling_compile_guard": guard_s.report(),
-        "host_bubble_fraction": bubble,
         "compile_guard": greport,
         "admit_syncs": syncs,
         "metrics": rt.metrics_snapshot(),
@@ -294,9 +281,7 @@ def run_csv(quick: bool = False) -> List[str]:
     for policy, m in rows.items():
         out.append(
             f"serving/{policy},{m['mean_ttft_ms'] * 1e3:.1f},"
-            f"tok_per_s={m['tok_per_s']:.1f} served={m['served']}"
-            + (f" host_bubble={m['host_bubble_frac']:.3f}"
-               if "host_bubble_frac" in m else ""))
+            f"tok_per_s={m['tok_per_s']:.1f} served={m['served']}")
     return out
 
 

@@ -65,8 +65,8 @@ def main():
                     help="write a Chrome-trace/Perfetto JSON timeline of "
                          "the replay (open at https://ui.perfetto.dev): "
                          "one track per decode slot, a queue track, a "
-                         "host dispatch track, and a wall-clock host-"
-                         "plan/device-execute track")
+                         "host dispatch track, and a wall-clock runtime "
+                         "track of the runtime's own steps (runtime.*)")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write the runtime metrics snapshot as JSON "
                          "(counters, pool/slot gauges, TTFT/TPOT "
@@ -205,9 +205,12 @@ def main():
           f"(fixed shapes -> exactly 1 each)")
     snap = rt.metrics_snapshot()
     h = snap["histograms"]
-    print(f"host-bubble fraction: {snap['host_bubble_fraction']:.3f} "
-          f"over {snap['dispatches']} dispatches "
-          f"(device idle while the host plans)")
+    live, chunks = (snap["counters"][k] for k in (
+        "prefill_live_rows", "prefill_chunks"))
+    if chunks:
+        print(f"prefill rows: {live} real in {chunks} dispatches of "
+              f"{rt.scfg.prefill_rows} rows ({live / chunks:.2f} a "
+              f"dispatch; the rest are garbage rows of partial groups)")
     if "ttft_s" in h and "tpot_s" in h:      # empty on a zero-serve trace
         print(f"TTFT p50/p95/p99: {h['ttft_s']['p50'] * 1e3:.1f}/"
               f"{h['ttft_s']['p95'] * 1e3:.1f}/"

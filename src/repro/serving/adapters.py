@@ -37,6 +37,7 @@ pure host-side operation.
 """
 from __future__ import annotations
 
+import time
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -44,6 +45,7 @@ import jax.numpy as jnp
 
 from repro.core.lora import combine_lora, partition_lora
 from repro.serving.faults import retry_with_backoff
+from repro.serving.telemetry import TRACK_RUNTIME
 
 _IS_NONE = {"is_leaf": lambda x: x is None}
 
@@ -274,7 +276,7 @@ class AdapterRegistry:
     def _event(self, counter: str, span: str, name: str, slot: int) -> None:
         rt = self.runtime
         rt.stats[counter] += 1
-        if rt.telemetry is not None:
-            t = rt._timer()
-            rt.telemetry.instant(span, "host", t, adapter=name, slot=slot,
-                                 pool_cached=rt.pool.num_cached)
+        tel = rt.telemetry
+        if tel is not None:
+            tel.instant(span, TRACK_RUNTIME, time.perf_counter(), adapter=name,
+                        slot=slot, pool_cached=rt.pool.num_cached)

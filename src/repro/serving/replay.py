@@ -12,6 +12,7 @@ allows.  Numbers come out directly comparable with
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -45,6 +46,21 @@ def synth_prompts(workload: Sequence[Dict], vocab: int, seed: int = 0
             for w in workload}
 
 
+def _restores_recorder(replay):
+    """Give the runtime back the recorder it had, however ``replay``
+    returns or raises, so an attached ``telemetry`` (and its ``gc``
+    hook) never outlives the call."""
+    @functools.wraps(replay)
+    def wrapped(runtime: ContinuousRuntime, *args, **kwargs):
+        prior = runtime.telemetry
+        try:
+            return replay(runtime, *args, **kwargs)
+        finally:
+            runtime.telemetry = prior
+    return wrapped
+
+
+@_restores_recorder
 def replay_trace(runtime: ContinuousRuntime, workload: Sequence[Dict],
                  fn_adapter: Dict[str, int], *, seed: int = 0,
                  prefill_group: Optional[int] = None,
@@ -63,10 +79,11 @@ def replay_trace(runtime: ContinuousRuntime, workload: Sequence[Dict],
     prompts are synthesized from the trace lengths (pass real prompts to
     exercise cross-request prefix sharing — e.g. a common system prompt).
 
-    ``telemetry`` attaches a span recorder: request-lifecycle spans
-    (queued / prefill / decode, finish / abandon / reject / abort / stall)
-    are stamped on the virtual clock, dispatch wall windows flow in from
-    the runtime, and ``telemetry.chrome_trace()`` afterwards yields a
+    ``telemetry`` attaches a span recorder for the replay's duration:
+    request-lifecycle spans (queued / prefill / decode, finish / abandon
+    / reject / abort / stall) are stamped on the virtual clock, the
+    runtime records its own steps on the wall clock (``runtime.*``
+    spans), and ``telemetry.chrome_trace()`` afterwards yields a
     Perfetto-loadable timeline.  Recording never changes replay results —
     the runtime takes identical timer readings either way (asserted
     bitwise in tests/test_telemetry.py).  TTFT / TPOT / queue-wait
@@ -342,8 +359,8 @@ def replay_trace(runtime: ContinuousRuntime, workload: Sequence[Dict],
                    else faults.dispatch_dt("prefill", t_disp, res.dt))
             now += pdt
             if tel is not None:
-                tel.span("dispatch:prefill", tm.TRACK_HOST, t_disp, now,
-                         requests=len(batch))
+                tel.stamp("dispatch:prefill", tm.TRACK_HOST, t_disp, now,
+                          requests=len(batch))
             for i, r in enumerate(batch):
                 r.dispatch = max(t_disp, r.arrival)   # clamp fp jitter from
                 r.first_token = now                   # the arrival-jump slack
@@ -360,22 +377,22 @@ def replay_trace(runtime: ContinuousRuntime, workload: Sequence[Dict],
                     # arrival) is reconstructible from the spans alone
                     track = (f"slot{res.slot_ids[i]}"
                              if res.slot_ids[i] >= 0 else tm.TRACK_QUEUE)
-                    tel.span(tm.SPAN_QUEUED, tm.TRACK_QUEUE, r.arrival,
-                             r.dispatch, req_id=r.req_id)
+                    tel.stamp(tm.SPAN_QUEUED, tm.TRACK_QUEUE, r.arrival,
+                              r.dispatch, req_id=r.req_id)
                     if resumed:
                         # the preempt -> re-admission arc: backoff + queue
                         # wait on the queue track, then a resume marker on
                         # the slot that picked the request back up
-                        tel.span(tm.SPAN_REQUEUED, tm.TRACK_QUEUE,
-                                 r.breakdown["preempt_t"], r.dispatch,
-                                 req_id=r.req_id)
+                        tel.stamp(tm.SPAN_REQUEUED, tm.TRACK_QUEUE,
+                                  r.breakdown["preempt_t"], r.dispatch,
+                                  req_id=r.req_id)
                         tel.instant(tm.EVT_RESUME, track, now,
                                     req_id=r.req_id, shared_blocks=shared)
-                    tel.span(tm.SPAN_PREFILL, track, r.dispatch, now,
-                             req_id=r.req_id, prompt_len=r.prompt_len,
-                             shared_blocks=shared,
-                             **{tm.ARG_SAMPLING_MODE:
-                                mode_of(r.req_id)})
+                    tel.stamp(tm.SPAN_PREFILL, track, r.dispatch, now,
+                              req_id=r.req_id, prompt_len=r.prompt_len,
+                              shared_blocks=shared,
+                              **{tm.ARG_SAMPLING_MODE:
+                                 mode_of(r.req_id)})
                 if resumed:
                     log("resume", r.req_id, res.slot_ids[i],
                         f"{shared} prefix blocks recovered from cache")
@@ -419,8 +436,8 @@ def replay_trace(runtime: ContinuousRuntime, workload: Sequence[Dict],
                else faults.dispatch_dt("decode", chunk_t0, dres.dt))
         now += ddt
         if tel is not None:
-            tel.span("dispatch:decode", tm.TRACK_HOST, chunk_t0, now,
-                     rows=len(dres.emitted))
+            tel.stamp("dispatch:decode", tm.TRACK_HOST, chunk_t0, now,
+                      rows=len(dres.emitted))
         finishing = {st.sid for st in dres.finished}
         for sid, toks in dres.emitted.items():
             st = runtime.slots.states[sid]
@@ -428,9 +445,9 @@ def replay_trace(runtime: ContinuousRuntime, workload: Sequence[Dict],
             if req is None or not toks:
                 continue
             if tel is not None:
-                tel.span(tm.SPAN_DECODE, f"slot{sid}", chunk_t0, now,
-                         req_id=req.req_id, tokens=len(toks),
-                         **{tm.ARG_SAMPLING_MODE: mode_of(req.req_id)})
+                tel.stamp(tm.SPAN_DECODE, f"slot{sid}", chunk_t0, now,
+                          req_id=req.req_id, tokens=len(toks),
+                          **{tm.ARG_SAMPLING_MODE: mode_of(req.req_id)})
             if sid in finishing:
                 # the chunk was (possibly) clipped by budget/EOS, but the
                 # device still ran the full chunk: the last accepted token

@@ -79,11 +79,13 @@ blocks) there is nothing safely shareable.
 
 Observability: every runtime owns a ``serving.metrics.MetricsRegistry``
 (``self.metrics``; ``self.stats`` is the legacy int-dict view over its
-counters), samples occupancy gauges at each scheduling boundary, records
-the wall window of every device dispatch for the host-bubble fraction,
-and — when a ``serving.telemetry.Telemetry`` recorder is attached —
-forwards per-dispatch wall records for the Chrome-trace export.  All
-measurement uses an injectable ``timer`` and the SAME timer-call sequence
+counters) and samples occupancy gauges at each scheduling boundary.
+When a ``serving.telemetry.Telemetry`` recorder is attached
+(``self.telemetry``), every step of ``try_admit`` and ``decode()`` runs
+inside a named span on the recorder's own clock (``runtime.admit.plan``,
+``runtime.prefill.round``, ``runtime.decode.sync``, ...), and Python
+collections are recorded as ``runtime.gc`` spans.  Dispatch latencies
+use an injectable ``timer``, and the runtime makes the SAME timer calls
 whether or not a recorder is attached, so telemetry can never perturb
 replay results (see docs/observability.md).
 """
@@ -93,6 +95,7 @@ import dataclasses
 import math
 import time
 import warnings
+import weakref
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -111,8 +114,9 @@ from repro.serving.kv_pool import BlockPool, blocks_for_tokens
 from repro.serving.metrics import MetricsRegistry
 from repro.serving.prefix import PrefixCache
 from repro.serving.slots import SlotState, SlotTable
-from repro.serving.telemetry import (EVT_ABORT, EVT_PREEMPT, Telemetry,
-                                     host_bubble_fraction)
+from repro.serving import telemetry as tm
+from repro.serving.telemetry import (EVT_ABORT, EVT_PREEMPT, NO_SPAN,
+                                     Telemetry)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -378,13 +382,13 @@ class ContinuousRuntime:
     def __init__(self, cfg: ModelConfig, params, scfg: ServingConfig, *,
                  telemetry: Optional[Telemetry] = None,
                  timer: Callable[[], float] = time.perf_counter):
-        """``telemetry`` attaches an optional span recorder (dispatch wall
-        windows flow into it; ``replay_trace`` stamps lifecycle spans).
-        ``timer`` is the wall clock used for EVERY latency measurement —
-        injectable so tests can replay under a deterministic fake clock
-        and assert bitwise-identical results with telemetry on vs off.
-        The runtime takes the same timer readings whether or not a
-        recorder is attached, so attaching one never perturbs timings."""
+        """``telemetry`` attaches an optional span recorder (the runtime
+        records its steps into it; ``replay_trace`` stamps lifecycle
+        spans).  ``timer`` is the clock of every dispatch latency the
+        runtime acts on — injectable so tests can replay under a
+        deterministic fake clock and assert bitwise-identical results
+        with telemetry on vs off.  The recorder reads its own clock, so
+        attaching one never changes the runtime's timer readings."""
         reason = paging_unsupported_reason(cfg)
         if reason is not None:
             raise ValueError(reason)
@@ -426,6 +430,8 @@ class ContinuousRuntime:
             # prefix index maps them; eviction drops the mapping
             self.pool.cache_hook = self.prefix.has_block
             self.pool.evict_hook = self.prefix.forget_block
+        self._telemetry: Optional[Telemetry] = None
+        self._detach_telemetry: Optional[weakref.finalize] = None
         self.telemetry = telemetry
         self._timer = timer
         # typed metrics registry; ``stats`` is the legacy int-dict
@@ -443,6 +449,8 @@ class ContinuousRuntime:
             ("shared_tokens", "prompt tokens covered by shared blocks"),
             ("shared_block_maps", "table entries mapped via sharing"),
             ("prefill_chunks", "chunked-prefill dispatches"),
+            ("prefill_live_rows", "real (non-garbage) rows of every "
+             "chunked-prefill dispatch"),
             ("decode_chunks", "jitted decode-chunk dispatches"),
             ("stall_steps", "slot-chunks discarded on pool exhaustion "
              "(one per stalled slot per decode dispatch)"),
@@ -507,10 +515,6 @@ class ContinuousRuntime:
         # (AdapterRegistry, checkpoint.store callers) can consult it; None
         # (the default) costs one attribute test per load
         self.faults = None           # Optional[faults.FaultPlan]
-        # host-bubble accounting: wall windows of every post-warmup device
-        # dispatch (jitted call + result sync).  Always recorded — the
-        # bubble fraction is a metric, not a telemetry feature.
-        self._dispatch_windows: List[Tuple[float, float]] = []
 
         sampled_serve = make_sampled_serve_step(cfg)
         chunk_step = make_chunked_prefill_step(cfg)
@@ -563,6 +567,26 @@ class ContinuousRuntime:
 
         self._decode = jax.jit(decode_chunk, donate_argnums=(2,))
         self._prefill = jax.jit(prefill_chunk, donate_argnums=(5,))
+
+    @property
+    def telemetry(self) -> Optional[Telemetry]:
+        """The attached span recorder, or None."""
+        return self._telemetry
+
+    @telemetry.setter
+    def telemetry(self, tel: Optional[Telemetry]) -> None:
+        """Attach (or with None, detach) a recorder; its ``runtime.gc``
+        hook is installed while it is attached, and removed as well when
+        the runtime is collected with the recorder still attached."""
+        if tel is self._telemetry:
+            return
+        if self._detach_telemetry is not None:
+            self._detach_telemetry()
+            self._detach_telemetry = None
+        self._telemetry = tel
+        if tel is not None:
+            tel.attach()
+            self._detach_telemetry = weakref.finalize(self, tel.detach)
 
     # ------------------------------------------------------------ capacity
     def max_output_for(self, prompt_len: int) -> int:
@@ -711,7 +735,7 @@ class ContinuousRuntime:
                         "(Request, prompt_tokens, adapter) tuples to "
                         "try_admit are deprecated; pass ServeRequest "
                         "objects (adapter by registry name)",
-                        DeprecationWarning, stacklevel=3)
+                        DeprecationWarning, stacklevel=4)
                     warned = True
                 req, prompt, adapter = it
                 sp = GREEDY
@@ -792,7 +816,7 @@ class ContinuousRuntime:
         reads zero state because it starts at position 0).  Returns the
         per-item first output tokens, sampled in-step (RNG counter 0)
         from each item's final chunk logit."""
-        scfg = self.scfg
+        scfg, tel = self.scfg, self._telemetry
         bs, C = scfg.block_size, scfg.prefill_chunk
         G, MB = scfg.prefill_rows, scfg.max_blocks_per_slot
         assert 0 < len(items) <= G
@@ -822,54 +846,60 @@ class ContinuousRuntime:
         toks_by_round: Dict[int, Any] = {}   # final rounds only: the (G,)
         #   sampled tokens (the retired path held (G, V) logits here)
         for r in range(max(len(s) for s in starts)):
-            tok = np.zeros((G, C), np.int32)
-            start = np.zeros((G,), np.int32)
-            last_idx = np.zeros((G,), np.int32)
-            ai = np.zeros((G,), np.int32)
-            ids = np.full((G, nb_c), GARBAGE_BLOCK, np.int32)
-            tbl = np.full((G, MB), -1, np.int32)
-            srows = np.full((G,), self.garbage_state_row, np.int32)
-            for i, (prompt, adapter, blocks, cov, sid, _, _) \
-                    in enumerate(items):
-                if r >= len(starts[i]):
-                    continue             # finished: garbage row
-                c0 = starts[i][r]
-                L = len(prompt)
-                n_real = min(C, L - c0)
-                tok[i, :n_real] = prompt[c0:c0 + n_real]
-                start[i] = c0
-                last_idx[i] = min(max(L - 1 - c0, 0), C - 1)
-                ai[i] = adapter
-                tbl[i, : len(blocks)] = blocks
-                srows[i] = sid
-                for jj in range(nb_c):
-                    j = c0 // bs + jj
-                    # skip shared blocks (they already hold exactly these
-                    # K/V and may be mapped by other slots) and
-                    # out-of-range blocks (chunk-tail junk past the last
-                    # allocated position)
-                    if cov <= j < len(blocks):
-                        ids[i, jj] = blocks[j]
-            first, self.cache = self._prefill(
-                self.params, jnp.asarray(tok), jnp.asarray(start),
-                jnp.asarray(last_idx), jnp.asarray(ai), self.cache,
-                jnp.asarray(ids), jnp.asarray(tbl), jnp.asarray(srows),
-                jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
-                jnp.asarray(seed))
-            if r in final_rounds:
-                # start the D2H transfer now so it overlaps the remaining
-                # prefill rounds instead of stalling at the sync below
-                first.copy_to_host_async()
-                toks_by_round[r] = first
+            live = sum(1 for s in starts if r < len(s))
+            with NO_SPAN if tel is None else tel.span(
+                    tm.SPAN_RT_PREFILL_ROUND, round=r, live_rows=live):
+                tok = np.zeros((G, C), np.int32)
+                start = np.zeros((G,), np.int32)
+                last_idx = np.zeros((G,), np.int32)
+                ai = np.zeros((G,), np.int32)
+                ids = np.full((G, nb_c), GARBAGE_BLOCK, np.int32)
+                tbl = np.full((G, MB), -1, np.int32)
+                srows = np.full((G,), self.garbage_state_row, np.int32)
+                for i, (prompt, adapter, blocks, cov, sid, _, _) \
+                        in enumerate(items):
+                    if r >= len(starts[i]):
+                        continue             # finished: garbage row
+                    c0 = starts[i][r]
+                    L = len(prompt)
+                    n_real = min(C, L - c0)
+                    tok[i, :n_real] = prompt[c0:c0 + n_real]
+                    start[i] = c0
+                    last_idx[i] = min(max(L - 1 - c0, 0), C - 1)
+                    ai[i] = adapter
+                    tbl[i, : len(blocks)] = blocks
+                    srows[i] = sid
+                    for jj in range(nb_c):
+                        j = c0 // bs + jj
+                        # skip shared blocks (they already hold exactly
+                        # these K/V and may be mapped by other slots) and
+                        # out-of-range blocks (chunk-tail junk past the
+                        # last allocated position)
+                        if cov <= j < len(blocks):
+                            ids[i, jj] = blocks[j]
+                first, self.cache = self._prefill(
+                    self.params, jnp.asarray(tok), jnp.asarray(start),
+                    jnp.asarray(last_idx), jnp.asarray(ai), self.cache,
+                    jnp.asarray(ids), jnp.asarray(tbl), jnp.asarray(srows),
+                    jnp.asarray(temp), jnp.asarray(top_k),
+                    jnp.asarray(top_p), jnp.asarray(seed))
+                if r in final_rounds:
+                    # start the D2H transfer now so it overlaps the
+                    # remaining prefill rounds instead of stalling at the
+                    # sync below
+                    first.copy_to_host_async()
+                    toks_by_round[r] = first
             self.stats["prefill_chunks"] += 1
+            self.stats["prefill_live_rows"] += live
         # One whole-batch transfer per final round, then index on host.
         # The per-item ``np.asarray(logits[r])`` loop this replaces was
         # reprolint's first real RL002 hit: a device sync inside a
         # Python loop, serializing admission against the device.
         self.stats["admit_syncs"] += len(toks_by_round)
-        synced: Dict[int, np.ndarray] = {
-            r: np.asarray(t)  # reprolint: sync-point (token emission)
-            for r, t in toks_by_round.items()}
+        with NO_SPAN if tel is None else tel.span(tm.SPAN_RT_ADMIT_SYNC):
+            synced: Dict[int, np.ndarray] = {
+                r: np.asarray(t)  # reprolint: sync-point (token emission)
+                for r, t in toks_by_round.items()}
         for i in range(len(items)):
             firsts[i] = int(synced[len(starts[i]) - 1][i])
         return firsts
@@ -916,52 +946,67 @@ class ContinuousRuntime:
         recomputed.  The partially-filled tail block is never shared: the
         new request gets a private copy filled by its own chunk loop."""
         assert len(items) > 0
-        resolved, rejected = self._coerce_admit_items(items)
-        kept: List[Tuple[Request, np.ndarray, int, SamplingParams, int]] \
-            = []
-        for it in resolved:
-            req, prompt = it[0], it[1]
-            if self.fits(len(prompt), max(req.output_len, 1)):
-                kept.append(it)
-            else:
-                self.reject_too_long(req)
-                rejected.append(req)
-        if now is not None and kept:
-            # deadline shedding — only requests that OPTED IN by setting a
-            # finite deadline are ever considered, and only a provable
-            # miss sheds (lower-bound estimates; no data -> no shedding)
-            shed_checked: List[Tuple[Request, np.ndarray, int,
-                                     SamplingParams, int]] = []
-            for it in kept:
-                req, prompt, adapter = it[0], it[1], it[2]
-                d_ttft, d_e2e = req.deadline_ttft, req.deadline_e2e
-                if not (math.isfinite(d_ttft) or math.isfinite(d_e2e)):
-                    shed_checked.append(it)
-                    continue
-                cov = (self.prefix.covered_tokens(adapter, prompt)
-                       if self.prefix is not None else 0)
-                floors = self.deadline_floors(
-                    len(prompt), max(req.output_len, 1), cov)
-                if floors is None:
-                    shed_checked.append(it)
-                    continue
-                waited = now - req.arrival
-                if waited + floors[0] > d_ttft \
-                        or waited + floors[1] > d_e2e:
-                    self.reject_deadline(req)
-                    rejected.append(req)
+        tel = self._telemetry
+        if tel is None:
+            return self._admit(items, now, None)
+        with tel.span(tm.SPAN_RT_ADMIT) as sp:
+            res = self._admit(items, now, tel)
+            sp.args["req_ids"] = [
+                (it.request if isinstance(it, ServeRequest) else it[0]).req_id
+                for it in items]
+            sp.args["admitted"] = 0 if res is None else len(res.first_tokens)
+        return res
+
+    def _admit(self, items: Sequence[Any], now: Optional[float],
+               tel: Optional[Telemetry]) -> Optional[AdmitResult]:
+        """``try_admit``'s body; ``tel`` is the attached recorder or None."""
+        with NO_SPAN if tel is None else tel.span(tm.SPAN_RT_ADMIT_PLAN):
+            resolved, rejected = self._coerce_admit_items(items)
+            kept: List[Tuple[Request, np.ndarray, int, SamplingParams, int]] \
+                = []
+            for it in resolved:
+                req, prompt = it[0], it[1]
+                if self.fits(len(prompt), max(req.output_len, 1)):
+                    kept.append(it)
                 else:
-                    shed_checked.append(it)
-            kept = shed_checked
-        if not kept:
-            return AdmitResult([], [], [], 0.0, rejected=rejected)
-        scfg = self.scfg
-        free = self.slots.free_slots()
-        if len(kept) > len(free):
-            return None
-        planned = self._plan_blocks(kept)
-        if planned is None:
-            return None
+                    self.reject_too_long(req)
+                    rejected.append(req)
+            if now is not None and kept:
+                # deadline shedding — only requests that OPTED IN by setting a
+                # finite deadline are ever considered, and only a provable
+                # miss sheds (lower-bound estimates; no data -> no shedding)
+                shed_checked: List[Tuple[Request, np.ndarray, int,
+                                         SamplingParams, int]] = []
+                for it in kept:
+                    req, prompt, adapter = it[0], it[1], it[2]
+                    d_ttft, d_e2e = req.deadline_ttft, req.deadline_e2e
+                    if not (math.isfinite(d_ttft) or math.isfinite(d_e2e)):
+                        shed_checked.append(it)
+                        continue
+                    cov = (self.prefix.covered_tokens(adapter, prompt)
+                           if self.prefix is not None else 0)
+                    floors = self.deadline_floors(
+                        len(prompt), max(req.output_len, 1), cov)
+                    if floors is None:
+                        shed_checked.append(it)
+                        continue
+                    waited = now - req.arrival
+                    if waited + floors[0] > d_ttft \
+                            or waited + floors[1] > d_e2e:
+                        self.reject_deadline(req)
+                        rejected.append(req)
+                    else:
+                        shed_checked.append(it)
+                kept = shed_checked
+            if not kept:
+                return AdmitResult([], [], [], 0.0, rejected=rejected)
+            scfg = self.scfg
+            free = self.slots.free_slots()
+            if len(kept) > len(free):
+                return None
+            planned = self._plan_blocks(kept)
+            if planned is None:
+                return None
         plans, registered = planned
 
         # Grouped rows of one dispatch read the pool the SAME round they
@@ -994,11 +1039,9 @@ class ContinuousRuntime:
                           + [[i] for i in dependent]):
             if not batch_idx:
                 continue
-            # one dispatch window per prefill group: [w0, w1] brackets the
+            # one dispatch time per prefill group: [w0, w1] brackets the
             # group's whole chunk loop incl. the final logit sync (the
-            # per-round host array prep rides inside — the loop never
-            # releases the device between rounds, so the window is the
-            # honest device-busy bracket for host-bubble accounting)
+            # per-round host array prep rides inside)
             w0 = self._timer()
             got = self._chunk_prefill(
                 [(kept[i][1], kept[i][2], plans[i][0] + plans[i][1],
@@ -1006,68 +1049,66 @@ class ContinuousRuntime:
                  for i in batch_idx])
             w1 = self._timer()
             total_dt += w1 - w0
-            self._dispatch_windows.append((w0, w1))
-            if self.telemetry is not None:
-                self.telemetry.record_dispatch("prefill", w0, w1,
-                                               rows=len(batch_idx))
             self.metrics.histogram(
                 "prefill_dispatch_s",
                 "wall seconds per prefill group dispatch").observe(w1 - w0)
             firsts.update(zip(batch_idx, got))
 
-        slot_ids, first_tokens, finished = [], [], []
-        for i, (req, prompt, adapter, sp, sd) in enumerate(kept):
-            shared, fresh = plans[i]
-            L = len(prompt)
-            first = firsts[i]
-            self.stats["prompt_tokens"] += L
-            # the prefill token is output position 0 — bucket it by mode
-            self.stats[f"tokens_mode_{sp.mode()}"] += 1
-            if not sp.greedy:
-                self.stats["sampled_tokens"] += 1
-            cov = len(shared) * bs
-            self.stats["shared_tokens"] += cov
-            self.stats["prefill_tokens"] += L - cov
-            self.stats["shared_block_maps"] += len(shared)
-            if req.breakdown.get("preempted"):
-                # resume accounting: a preempted request re-admitting —
-                # shared coverage here IS the cheap-resume payoff (its
-                # demoted blocks survived in the cached-LRU tier)
-                if shared:
-                    self.stats["resume_prefix_hits"] += 1
-                req.breakdown["resumed_covered_tokens"] = float(cov)
-                start_tok = 0 if self.has_state \
-                    else min(cov, ((L - 1) // bs) * bs)
-                req.breakdown["resume_recomputed_tokens"] = \
-                    float(L - start_tok)
+        with NO_SPAN if tel is None else tel.span(tm.SPAN_RT_ADMIT_BIND):
+            slot_ids, first_tokens, finished = [], [], []
+            for i, (req, prompt, adapter, sp, sd) in enumerate(kept):
+                shared, fresh = plans[i]
+                L = len(prompt)
+                first = firsts[i]
+                self.stats["prompt_tokens"] += L
+                # the prefill token is output position 0 — bucket it by mode
+                self.stats[f"tokens_mode_{sp.mode()}"] += 1
+                if not sp.greedy:
+                    self.stats["sampled_tokens"] += 1
+                cov = len(shared) * bs
+                self.stats["shared_tokens"] += cov
+                self.stats["prefill_tokens"] += L - cov
+                self.stats["shared_block_maps"] += len(shared)
+                if req.breakdown.get("preempted"):
+                    # resume accounting: a preempted request re-admitting —
+                    # shared coverage here IS the cheap-resume payoff (its
+                    # demoted blocks survived in the cached-LRU tier)
+                    if shared:
+                        self.stats["resume_prefix_hits"] += 1
+                    req.breakdown["resumed_covered_tokens"] = float(cov)
+                    start_tok = 0 if self.has_state \
+                        else min(cov, ((L - 1) // bs) * bs)
+                    req.breakdown["resume_recomputed_tokens"] = \
+                        float(L - start_tok)
 
-            sid = sids[i]
-            st = SlotState(sid=sid, req=req, adapter=adapter, prompt_len=L,
-                           budget=max(req.output_len, 1), pos=L,
-                           blocks=shared + fresh, last_token=first,
-                           shared=len(shared), prompt_tokens=prompt,
-                           history=[first], sampling=sp, seed=sd)
-            first_tokens.append(first)
-            done = st.budget == 1 or (scfg.eos_id is not None
-                                      and first == scfg.eos_id)
-            if done:
-                # finished at prefill: never bound, so free[i] would be a
-                # lie — report -1 (the slot stays free for other requests).
-                # The free is a refcount drop: registered prompt blocks park
-                # in the pool's cached LRU for future admits to share.
-                st.sid = -1
-                slot_ids.append(-1)
-                self.pool.free(st.blocks)
-                finished.append(st)
-            else:
-                slot_ids.append(sid)
-                self.slots.bind(st, first)
-                if self.adapters is not None:
-                    # in-flight requests pin their adapter: unload/swap of
-                    # a bank slot some live decode row still reads would
-                    # change that request's results mid-stream
-                    self.adapters.pin(adapter)
-        self._sample_gauges()
+                sid = sids[i]
+                st = SlotState(sid=sid, req=req, adapter=adapter, prompt_len=L,
+                               budget=max(req.output_len, 1), pos=L,
+                               blocks=shared + fresh, last_token=first,
+                               shared=len(shared), prompt_tokens=prompt,
+                               history=[first], sampling=sp, seed=sd)
+                first_tokens.append(first)
+                done = st.budget == 1 or (scfg.eos_id is not None
+                                          and first == scfg.eos_id)
+                if done:
+                    # finished at prefill: never bound, so free[i] would be
+                    # a lie — report -1 (the slot stays free for other
+                    # requests).  The free is a refcount drop: registered
+                    # prompt blocks park in the pool's cached LRU for
+                    # future admits to share.
+                    st.sid = -1
+                    slot_ids.append(-1)
+                    self.pool.free(st.blocks)
+                    finished.append(st)
+                else:
+                    slot_ids.append(sid)
+                    self.slots.bind(st, first)
+                    if self.adapters is not None:
+                        # in-flight requests pin their adapter: unload/swap of
+                        # a bank slot some live decode row still reads would
+                        # change that request's results mid-stream
+                        self.adapters.pin(adapter)
+            self._sample_gauges()
         return AdmitResult(slot_ids, first_tokens, finished, total_dt,
                            shared_blocks=[len(p[0]) for p in plans],
                            rejected=rejected)
@@ -1264,48 +1305,68 @@ class ContinuousRuntime:
         write to the garbage block and are ignored)."""
         if self.slots.num_active == 0:
             return None
+        scfg, tel = self.scfg, self._telemetry
+        with NO_SPAN if tel is None else tel.span(
+                tm.SPAN_RT_DECODE, rows=self.slots.num_active):
+            with NO_SPAN if tel is None else tel.span(
+                    tm.SPAN_RT_DECODE_PLAN):
+                stalled, aborted, preempted = self._ensure_blocks()
+            # a stall step = one slot riding one chunk with discarded
+            # outputs
+            self.stats["stall_steps"] += len(stalled)
+            if self.slots.num_active == 0:  # everything aborted/preempted
+                return DecodeResult({}, [], aborted, stalled, 0.0,
+                                    preempted=preempted)
+
+            # Stalled slots run the chunk unmodified from (pending token,
+            # pos): every KV position the stalled chunk writes is
+            # re-written by the resumed chunk before it can be attended
+            # (decode writes position pos+t at scan step t, then attends
+            # <= pos+t), writes past the allocated suffix clip to the
+            # garbage block, and the slot's REC/SSD state row is
+            # redirected to the garbage state row (slots.state_rows) so
+            # the recurrence cannot advance twice — so discarding the
+            # outputs and not advancing pos is a true no-op.
+            with NO_SPAN if tel is None else tel.span(
+                    tm.SPAN_RT_DECODE_STAGE):
+                t0 = self._timer()
+                toks, self.cache = self._decode(
+                    self.params, jnp.asarray(self.slots.tokens), self.cache,
+                    jnp.asarray(self.slots.pos),
+                    jnp.asarray(self.slots.block_tbl),
+                    jnp.asarray(self.slots.adapter),
+                    jnp.asarray(self.slots.state_rows(
+                        self.garbage_state_row)),
+                    jnp.asarray(self.slots.temp),
+                    jnp.asarray(self.slots.top_k),
+                    jnp.asarray(self.slots.top_p),
+                    jnp.asarray(self.slots.seed),
+                    jnp.asarray(self.slots.rng_counter))
+            with NO_SPAN if tel is None else tel.span(
+                    tm.SPAN_RT_DECODE_SYNC):
+                toks = np.asarray(toks)  # reprolint: sync-point — (B, K)
+                #   token emission, the serving loop's one deliberate
+                #   decode sync
+                t1 = self._timer()
+            with NO_SPAN if tel is None else tel.span(
+                    tm.SPAN_RT_DECODE_EMIT):
+                dt = t1 - t0
+                self.stats["decode_chunks"] += 1
+                self.metrics.histogram(
+                    "decode_dispatch_s",
+                    "wall seconds per jitted decode-chunk dispatch"
+                ).observe(dt)
+                emitted, finished = self._emit(toks)
+                self._sample_gauges()
+        return DecodeResult(emitted, finished, aborted, stalled, dt,
+                            preempted=preempted)
+
+    def _emit(self, toks: np.ndarray
+              ) -> Tuple[Dict[int, List[int]], List[SlotState]]:
+        """Accept each running, unstalled slot's tokens of this chunk
+        (budget- and EOS-clipped), release the slots that finished and
+        advance the rest."""
         scfg = self.scfg
-        t_plan0 = self._timer()
-        stalled, aborted, preempted = self._ensure_blocks()
-        # a stall step = one slot riding one chunk with discarded outputs;
-        # ReplayEvent already logged these per-slot, the runtime never
-        # counted them (the ISSUE-6 counter-asymmetry satellite)
-        self.stats["stall_steps"] += len(stalled)
-        if self.slots.num_active == 0:      # everything aborted/preempted
-            return DecodeResult({}, [], aborted, stalled, 0.0,
-                                preempted=preempted)
-
-        # Stalled slots run the chunk unmodified from (pending token, pos):
-        # every KV position the stalled chunk writes is re-written by the
-        # resumed chunk before it can be attended (decode writes position
-        # pos+t at scan step t, then attends <= pos+t), writes past the
-        # allocated suffix clip to the garbage block, and the slot's
-        # REC/SSD state row is redirected to the garbage state row
-        # (slots.state_rows) so the recurrence cannot advance twice — so
-        # discarding the outputs and not advancing pos is a true no-op.
-        t0 = self._timer()
-        toks, self.cache = self._decode(
-            self.params, jnp.asarray(self.slots.tokens), self.cache,
-            jnp.asarray(self.slots.pos), jnp.asarray(self.slots.block_tbl),
-            jnp.asarray(self.slots.adapter),
-            jnp.asarray(self.slots.state_rows(self.garbage_state_row)),
-            jnp.asarray(self.slots.temp), jnp.asarray(self.slots.top_k),
-            jnp.asarray(self.slots.top_p), jnp.asarray(self.slots.seed),
-            jnp.asarray(self.slots.rng_counter))
-        toks = np.asarray(toks)  # reprolint: sync-point — (B, K) token
-        #   emission, the serving loop's one deliberate decode sync
-        t1 = self._timer()
-        dt = t1 - t0
-        self.stats["decode_chunks"] += 1
-        self._dispatch_windows.append((t0, t1))
-        if self.telemetry is not None:
-            self.telemetry.record_dispatch(
-                "decode", t0, t1, host_plan_s=t0 - t_plan0,
-                rows=self.slots.num_active)
-        self.metrics.histogram(
-            "decode_dispatch_s",
-            "wall seconds per jitted decode-chunk dispatch").observe(dt)
-
         emitted: Dict[int, List[int]] = {}
         finished: List[SlotState] = []
         for s in list(self.slots.active()):
@@ -1340,9 +1401,7 @@ class ContinuousRuntime:
                 # unchanged — the stall replay draws the same keys.
                 self.slots.rng_counter[s.sid] = s.produced
                 self._reclaim_window(s)
-        self._sample_gauges()
-        return DecodeResult(emitted, finished, aborted, stalled, dt,
-                            preempted=preempted)
+        return emitted, finished
 
     def _reclaim_window(self, s: SlotState) -> None:
         """Release blocks that slid fully out of the sliding window.
@@ -1527,19 +1586,12 @@ class ContinuousRuntime:
               "physical blocks indexed by the prefix trie").set(
                 len(self.prefix))
 
-    def host_bubble_fraction(self) -> float:
-        """Share of the wall interval between the first and last device
-        dispatch NOT covered by device work — host planning the device
-        waits on (the metric the ROADMAP async-overlap item is gated on).
-        0.0 until two dispatches exist; always in [0, 1]."""
-        return host_bubble_fraction(self._dispatch_windows)
-
     def metrics_snapshot(self) -> Dict[str, Any]:
         """One flat JSON-able dict of everything the runtime knows:
         counters (the legacy ``stats`` keys plus the new mirrors), gauge
-        summaries, latency histograms, compile-count mirrors, and the
-        host-bubble fraction.  Exporters (``--metrics-out``,
-        ``BENCH_serving.json``) call this once after a replay."""
+        summaries, latency histograms and compile-count mirrors.
+        Exporters (``--metrics-out``, ``BENCH_serving.json``) call this
+        once after a replay."""
         self._sample_gauges()
         self.metrics.gauge(
             "decode_compiles", "decode-step compile count (must be 1)"
@@ -1548,8 +1600,6 @@ class ContinuousRuntime:
             "prefill_compiles", "chunked-prefill compile count (must be 1)"
         ).set(self.prefill_compiles())
         snap = self.metrics.snapshot()
-        snap["host_bubble_fraction"] = self.host_bubble_fraction()
-        snap["dispatches"] = len(self._dispatch_windows)
         if self.telemetry is not None:
             snap["spans"] = len(self.telemetry.spans)
             snap["instant_events"] = len(self.telemetry.instants)

@@ -145,9 +145,10 @@ def test_legacy_tuple_admission_warns_and_still_works(runtime):
     rt = runtime
     rng = np.random.default_rng(2)
     r = _req(103, out=3)
-    with pytest.warns(DeprecationWarning, match="ServeRequest"):
+    with pytest.warns(DeprecationWarning, match="ServeRequest") as got:
         res = rt.try_admit(
             [(r, rng.integers(0, 64, 12, dtype=np.int32), 1)])
+    assert got[0].filename == __file__      # attributed to the caller
     assert res is not None and len(res.slot_ids) == 1
     _drain(rt)
     assert rt.pool.in_use == 0

@@ -29,11 +29,13 @@ ALL_RULES = ("RL001", "RL002", "RL003", "RL004", "RL005")
 
 DEFAULT_PLAN_FUNCTIONS = (
     "*:ContinuousRuntime.try_admit",
+    "*:ContinuousRuntime._admit",
     "*:ContinuousRuntime._plan_blocks",
     "*:ContinuousRuntime._chunk_prefill",
     "*:ContinuousRuntime._ensure_blocks",
     "*:ContinuousRuntime._reclaim_window",
     "*:ContinuousRuntime.decode",
+    "*:ContinuousRuntime._emit",
 )
 
 

@@ -6,8 +6,9 @@ while the device still runs batch N.  Any device->host materialization
 inside that planning code — ``np.asarray``/``np.array`` on a device
 array, ``jax.device_get``, ``.block_until_ready()``, or ``float()``
 over a dispatch result — stalls the host until the device drains,
-serializing the pipeline (this is exactly what the
-``host_bubble_fraction`` metric measures).
+serializing the pipeline: in a profiler trace the device then idles
+under whichever ``runtime.*`` span the host is in
+(docs/observability.md).
 
 The *plan region* is the set of scheduler methods configured via
 ``plan-functions`` (``[tool.reprolint]``), by default the
