@@ -1,11 +1,12 @@
 """Dispatch wrapper for paged decode attention.
 
 ``use_kernel=True`` picks the fastest block-table walk for the current
-backend: the Pallas TPU kernel (in-kernel table walk, no gathered K/V, no
-mask tensor in HBM) on TPU, or a fused jnp block-walk off-TPU that keeps
-the blocked (K, B, MB, bs, hd) operand layout end-to-end — no (B, MB*bs,
-K, hd) reshaped copy and no additive mask tensor, which measurably beats
-the legacy gather path on CPU as well.  ``use_kernel=False`` is the plain
+backend: the Pallas TPU kernel (each row walks only its live blocks, many
+pages a step; no gathered K/V, no mask tensor in HBM) on TPU, or a fused
+jnp block-walk off-TPU that keeps the blocked (K, B, MB, bs, hd) operand
+layout end-to-end — no (B, MB*bs, K, hd) reshaped copy and no additive
+mask tensor, which measurably beats the legacy gather path on CPU as
+well.  ``use_kernel=False`` is the plain
 gather reference (``ref.py``).  ``interpret=True`` forces the Pallas
 kernel in interpret mode so CPU tests exercise the real kernel logic.
 """
@@ -36,8 +37,9 @@ def _paged_decode_jnp(q, kp, vp, block_tbl, pos, *,
     Online-softmax ``fori_loop`` over logical blocks whose trip count is
     the GROUP's max live block count — ``max(pos) // bs + 1``, a traced
     scalar, so one compile covers every occupancy — instead of the full
-    table capacity MB (the kernel prunes in-grid on TPU; this is the
-    off-TPU analogue, same bound PR 5 gave the chunk-prefill walk).
+    table capacity MB.  Every row rides every step of that walk, one
+    block at a time; the TPU kernel instead bounds each row's walk by its
+    own position and window and fetches many pages a step.
     Blocks past every row's position are fully masked and contribute
     exact float identities (p masked to literal 0, corr = exp(0) = 1),
     so the bounded walk is bitwise-identical to ``full_walk=True`` (all
@@ -89,7 +91,7 @@ def _paged_decode_jnp(q, kp, vp, block_tbl, pos, *,
 
 
 def paged_decode_gqa(q, kp, vp, block_tbl, pos, *,
-                     window: Optional[int] = None, s_block: int = 512,
+                     window: Optional[int] = None,
                      use_kernel: bool = True,
                      interpret: Optional[bool] = None):
     """q: (B, H, hd); kp, vp: (K, NB, bs, hd); block_tbl: (B, MB) int32;
@@ -105,5 +107,5 @@ def paged_decode_gqa(q, kp, vp, block_tbl, pos, *,
     K = kp.shape[0]
     o = paged_decode_attention(q.reshape(B, K, H // K, hd), kp, vp,
                                block_tbl, pos, window=window,
-                               s_block=s_block, interpret=interpret)
+                               interpret=interpret)
     return o.reshape(B, H, hd)

@@ -11,8 +11,8 @@ prefix-shared blocks, earlier chunks, and the chunk itself — in one pass:
   **scalar-prefetched**; the BlockSpec index map resolves each logical
   block to its physical pool block, so the DMA engine streams exactly one
   (sub, hd) pool tile per step and no gathered K/V copy ever exists
-  (same design as ``paged_attention/paged_attn.py``, generalized from one
-  decode token to a q-chunk);
+  (the decode kernel, ``paged_attention/paged_attn.py``, instead walks
+  each row's live pages in-kernel, many per step);
 * the grid is (batch, kv-head, q-tile, logical-block, sub-block) with the
   two kv dims innermost ("arbitrary" -> VMEM scratch persists) and online
   softmax accumulates across them;

@@ -452,6 +452,11 @@ class ContinuousRuntime:
             ("prefill_live_rows", "real (non-garbage) rows of every "
              "chunked-prefill dispatch"),
             ("decode_chunks", "jitted decode-chunk dispatches"),
+            ("decode_kv_blocks", "K/V blocks the paged-decode kernel "
+             "fetches per layer and kv head, summed over the rows and scan "
+             "steps of every decode dispatch (its share of num_slots x "
+             "max_blocks_per_slot x decode_chunk x decode_chunks is the "
+             "share of the table the bounded walk covers)"),
             ("stall_steps", "slot-chunks discarded on pool exhaustion "
              "(one per stalled slot per decode dispatch)"),
             ("rejected_too_long", "requests dropped: prompt + output "
@@ -1327,6 +1332,7 @@ class ContinuousRuntime:
             # redirected to the garbage state row (slots.state_rows) so
             # the recurrence cannot advance twice — so discarding the
             # outputs and not advancing pos is a true no-op.
+            walked = self._decode_kv_blocks()
             with NO_SPAN if tel is None else tel.span(
                     tm.SPAN_RT_DECODE_STAGE):
                 t0 = self._timer()
@@ -1352,6 +1358,7 @@ class ContinuousRuntime:
                     tm.SPAN_RT_DECODE_EMIT):
                 dt = t1 - t0
                 self.stats["decode_chunks"] += 1
+                self.stats["decode_kv_blocks"] += walked
                 self.metrics.histogram(
                     "decode_dispatch_s",
                     "wall seconds per jitted decode-chunk dispatch"
@@ -1360,6 +1367,30 @@ class ContinuousRuntime:
                 self._sample_gauges()
         return DecodeResult(emitted, finished, aborted, stalled, dt,
                             preempted=preempted)
+
+    def _decode_kv_blocks(self) -> int:
+        """K/V blocks the paged-decode kernel fetches (per layer and kv
+        head) over the next dispatch's scan steps, from the slot mirrors
+        and the kernel's bounds (``kernels/paged_attention/paged_attn.py``):
+        a row walks its allocated table entries from its window's first
+        block to its position's block, and nothing where that block is
+        unallocated (an inactive slot, or a scan step past the row's
+        allocation)."""
+        tbl, bs, w = self.slots.block_tbl, self.scfg.block_size, \
+            self.cfg.sliding_window
+        B, MB = tbl.shape
+        held = np.zeros((B, MB + 1), np.int64)    # allocated entries < j
+        np.cumsum(tbl >= 0, axis=1, out=held[:, 1:])
+        rows = np.arange(B)
+        walked = 0
+        for t in range(self.scfg.decode_chunk):
+            pos = self.slots.pos.astype(np.int64) + t
+            last = np.minimum(pos // bs, MB - 1)
+            first = (np.zeros_like(last) if w is None else
+                     np.minimum(np.maximum(pos - w + 1, 0) // bs, last))
+            n = held[rows, last + 1] - held[rows, first]
+            walked += int(np.where(tbl[rows, last] >= 0, n, 0).sum())
+        return walked
 
     def _emit(self, toks: np.ndarray
               ) -> Tuple[Dict[int, List[int]], List[SlotState]]:

@@ -72,6 +72,22 @@ def test_paged_decode_compiles_for_v5e(one_chip, dtype):
     _assert_kernel(compiled)
 
 
+@pytest.mark.parametrize("window", [None, 2047])
+def test_paged_decode_compiles_at_chat_cell_shapes(one_chip, window):
+    """The benchmark's chat cell: 32 slots of 256 blocks over a 3072-block
+    pool, bf16.  The whole block table rides in SMEM and each compute step's
+    pages in double-buffered VMEM, so this is where an overflow of either
+    would show; a window changes the walk's first block."""
+    B, NB_CELL, MB_CELL = 32, 3072, 256
+    compiled = paged_decode_attention.lower(
+        _sds((B, K, G, HD), jnp.bfloat16, one_chip),
+        _sds((K, NB_CELL, BS, HD), jnp.bfloat16, one_chip),
+        _sds((K, NB_CELL, BS, HD), jnp.bfloat16, one_chip),
+        _sds((B, MB_CELL), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip), window=window).compile()
+    _assert_kernel(compiled)
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_paged_prefill_compiles_for_v5e(one_chip, dtype):
     B, C = 4, 128

@@ -116,7 +116,7 @@ def test_seeded_index_map_mutable_closure_caught(tmp_path):
     stay clean, so the flag is the seed, not noise)."""
     src = REPO / "src/repro/kernels/paged_attention/paged_attn.py"
     text = src.read_text()
-    old = "    def k_map(b, h, j, i, tbl, pos):"
+    old = "    def row_map(b, tbl, pos):"
     assert old in text
     seeded = '_SCHEDULE = [0]\n' + text.replace(
         old, old + "\n        _ = _SCHEDULE[0]")

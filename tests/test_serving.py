@@ -290,6 +290,39 @@ def test_replay_trace_end_to_end(llama_model):
     assert "admit" in kinds and "finish" in kinds
 
 
+@pytest.mark.parametrize("window", [None, 8])
+def test_decode_kv_blocks_counts_the_bounded_walk(llama_model, window):
+    """``decode_kv_blocks`` adds, per decode dispatch, the blocks the
+    paged-decode kernel walks: for each running row and scan step at
+    position p, blocks ``max(0, p - window + 1) // bs`` (0 without a
+    window) through ``p // bs``; the free slot adds nothing, and blocks
+    reclaimed behind the window are never in the walk."""
+    cfg, params = llama_model
+    if window is not None:
+        cfg = cfg.with_(sliding_window=window)
+    bs, chunk, n = 8, 4, 3
+    rt = make_runtime(cfg, params, num_slots=3, block_size=bs,
+                      decode_chunk=chunk)
+    lens = (12, 19)
+    rng = np.random.default_rng(0)
+    res = rt.try_admit([ServeRequest(
+        prompt=rng.integers(1, cfg.vocab_size, L).astype(np.int32),
+        max_new_tokens=24) for L in lens])
+    assert sorted(rt.slots.pos[res.slot_ids].tolist()) == sorted(lens)
+    assert rt.slots.num_active == 2             # one slot stays free
+    expected = 0
+    for d in range(n):
+        for L in lens:
+            for p in range(L + d * chunk, L + (d + 1) * chunk):
+                first = 0 if window is None else max(0, p - window + 1) // bs
+                expected += p // bs - first + 1
+        rt.decode()
+    assert rt.stats["decode_chunks"] == n
+    assert rt.stats["decode_kv_blocks"] == expected
+    if window is not None:
+        assert rt.stats["reclaimed_blocks"] > 0
+
+
 def test_oversized_request_rejected_gracefully(llama_model):
     """An oversized request mid-trace must not kill the replay: it is
     counted (stats + breakdown flag), reported failed, and every other
