@@ -57,7 +57,7 @@ def main() -> int:
         t1 = time.perf_counter()
         least = check.min_tokens(args.rehearse)
         g = check.gaps(cell.config, ref,
-                       check.reference_weights(system.backbone),
+                       ref.reference_weights(system.backbone),
                        lambda a: system.adapter_fn(model.adapter_key(
                            seed, a)),
                        check.sample(lg.recs, seed, min_tokens=least),

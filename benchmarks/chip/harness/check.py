@@ -82,27 +82,11 @@ def verdict(conf: Dict[str, Any], gap: float, tokens: int,
     return ok, checks
 
 
-def reference_weights(backbone) -> Dict[str, Any]:
-    """The reference's view of the benchmark's backbone: the one period of
-    layers (stacked), LoRA banks left out."""
-    layers = {k: v for k, v in backbone["periods"]["p0"].items()}
-    layers["attn"] = {k: v for k, v in layers["attn"].items()
-                      if k != "lora"}
-    w = {"embed": backbone["embed"], "layers": layers,
-         "final_norm": backbone["final_norm"]}
-    if "lm_head" in backbone:
-        w["lm_head"] = backbone["lm_head"]
-    return w
-
-
-def adapter_lora(adapter_tree) -> Dict[str, Any]:
-    return adapter_tree["periods"]["p0"]["attn"]["lora"]
-
-
 def gaps(conf: Dict[str, Any], ref, weights, adapter_of, recs, *,
          control: bool = False) -> Gaps:
-    """Widest gaps over ``recs``.  ``adapter_of(adapter id)`` regenerates
-    that adapter's LoRA tree from the seed."""
+    """Widest gaps over ``recs``.  ``weights``: the reference's
+    ``reference_weights`` of the backbone; ``adapter_of(adapter id)``
+    regenerates that adapter's tree from the seed."""
     spec = ref.spec_of(conf)
     worst, worst_c, n = 0.0, (0.0 if control else None), 0
     for r in recs:
@@ -110,15 +94,22 @@ def gaps(conf: Dict[str, Any], ref, weights, adapter_of, recs, *,
         L = len(r.prompt)
         seq = np.concatenate([r.prompt, served[:-1]]).astype(np.int32)
         read = np.arange(L - 1, L - 1 + len(served), dtype=np.int32)
-        lora = adapter_lora(adapter_of(r.adapter))
+        lora = ref.adapter_lora(adapter_of(r.adapter))
         z = ref.logits(conf, weights, lora, seq, read, spec=spec)
         best = z.max(axis=1)
         rows = np.arange(len(served))
-        worst = max(worst, float(np.max(best - z[rows, served])))
+        worst = _wider(worst, best - z[rows, served])
         if control:
             zc = ref.logits(conf, weights, lora, seq, read, spec=spec,
                             fp8=True)
             pick = zc.argmax(axis=1)
-            worst_c = max(worst_c, float(np.max(best - z[rows, pick])))
+            worst_c = _wider(worst_c, best - z[rows, pick])
         n += len(served)
     return Gaps(worst, worst_c, n, len(recs))
+
+
+def _wider(worst: float, gaps: np.ndarray) -> float:
+    """The wider of ``worst`` and the widest of ``gaps``; a gap that is not
+    finite (a NaN or infinite logit on either side) is infinitely wide."""
+    g = float(np.max(gaps))
+    return max(worst, g if np.isfinite(g) else float("inf"))

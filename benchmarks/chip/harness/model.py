@@ -10,6 +10,7 @@ needs after the window, so it takes nothing the program has made.
 from __future__ import annotations
 
 import dataclasses
+import inspect
 import math
 from typing import Any, Dict, Tuple
 
@@ -46,28 +47,48 @@ def rehearse_conf(conf: Dict[str, Any]) -> Dict[str, Any]:
 
 
 def model_config(conf: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file."""
+    """The program's ``ModelConfig`` for a configuration file: its published
+    keys, mapped to the program's fields, and every further field from the
+    block ``architecture.program``, passed through as is."""
     from repro.models.config import LoRAConfig, ModelConfig
     arch = conf["architecture"]
     window = conf.get("sliding_window")
     if conf.get("use_sliding_window") is False:
         window = None
     lora = conf["lora"]
-    cfg = ModelConfig(
-        name=conf["name"], family="dense",
-        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        num_heads=conf["num_attention_heads"],
+    return ModelConfig(
+        name=conf["name"], num_layers=conf["num_hidden_layers"],
+        d_model=conf["hidden_size"], num_heads=conf["num_attention_heads"],
         num_kv_heads=conf["num_key_value_heads"],
         d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
         head_dim=arch["head_dim"], qkv_bias=arch["qkv_bias"],
-        mlp_type="swiglu", norm_type="rmsnorm",
         tie_embeddings=conf["tie_word_embeddings"],
         rope_theta=float(conf["rope_theta"]), sliding_window=window,
         dtype=conf["torch_dtype"],
         lora=LoRAConfig(rank=lora["rank"], alpha=lora["alpha"],
                         targets=tuple(lora["targets"])),
-        source=conf["source"])
-    return cfg
+        source=conf["source"],
+        **_norm_eps(conf, ModelConfig), **arch["program"])
+
+
+def _norm_eps(conf: Dict[str, Any], model_config_cls) -> Dict[str, float]:
+    """``rms_norm_eps`` as the program's ``norm_eps`` where its
+    ``ModelConfig`` has that field.  A program that fixes the eps instead
+    runs only a configuration that states the same one: any other is
+    refused, so that no run checks a program at one eps against the
+    reference at another."""
+    eps = float(conf["rms_norm_eps"])
+    if "norm_eps" in {f.name for f in dataclasses.fields(model_config_cls)}:
+        return {"norm_eps": eps}
+    from repro.models.layers import apply_norm
+    fixed = inspect.signature(apply_norm).parameters["eps"].default
+    if eps != fixed:
+        raise ValueError(
+            f"{conf['name']}: the configuration states rms_norm_eps {eps}, "
+            f"and the program fixes its norms' eps at {fixed} (its "
+            f"ModelConfig has no norm_eps): it cannot run this "
+            f"configuration as published")
+    return {}
 
 
 def serving_shape(conf: Dict[str, Any], *, rehearse: bool = False

@@ -2,7 +2,8 @@
 
 End-to-end metrics come from the client's host-clock log; per-layer
 metrics from the log, the runtime's counters differenced at the window's
-edges, the operation counts of ``counts`` and the reduced trace.
+edges, the operation counts of the configuration's reference module and
+the reduced trace.
 """
 from __future__ import annotations
 
@@ -11,15 +12,16 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from . import counts
 from .client import Log
+from .counts import (ArchCounts, prefill_rows, roofline_seconds,
+                     sgmv_call)
 from .trace import Reduced
 
 
 @dataclasses.dataclass
 class Run:
     log: Log
-    dims: counts.Dims
+    counts: ArchCounts                  # the reference's ``counts_of``
     peaks: Dict[str, float]
     chunk: int                          # prefill chunk
     decode_chunk: int
@@ -100,42 +102,39 @@ class Run:
         return [a for a in self.log.admits if a[1] <= self.log.trace_end]
 
     def decode_flops(self) -> float:
-        return sum(counts.decode_model_flops(self.dims, c.pos, c.accepted)
+        return sum(self.counts.decode_flops(c.pos, c.accepted)
                    for c in self.decodes())
 
     def prefill_flops(self) -> float:
-        return sum(counts.prefill_model_flops(self.dims, a.prompt_len,
-                                              a.start)
+        return sum(self.counts.prefill_flops(a.prompt_len, a.start)
                    for _, _, adm in self.admits() for a in adm)
 
     def paged_decode_min_s(self) -> float:
-        d, k = self.dims, self.decode_chunk
-        return sum(d.L * counts.roofline_seconds(
-            *counts.paged_decode_call(d, [p + j for p in c.pos]), self.peaks)
-            for c in self.decodes() for j in range(k))
+        return sum(roofline_seconds(*self.counts.paged_decode_step(
+            [p + j for p in c.pos]), self.peaks)
+            for c in self.decodes() for j in range(self.decode_chunk))
 
     def sgmv_decode_min_s(self) -> float:
-        d, k = self.dims, self.decode_chunk
+        k, rank = self.decode_chunk, self.counts.lora_rank
         t = 0.0
         for c in self.decodes():
             rows, ads = len(c.pos), len(set(c.adapters))
-            t += d.L * k * sum(counts.roofline_seconds(
-                *counts.sgmv_call(d, rows, ads, i, o), self.peaks)
-                for i, o in d.sites())
+            t += k * sum(layers * roofline_seconds(
+                *sgmv_call(rows, ads, i, o, rank), self.peaks)
+                for i, o, layers in self.counts.lora_sites())
         return t
 
     def paged_prefill_min_s(self) -> float:
         """Rounds of one admission that share an index ran side by side in
         one kernel call (a group); they are counted as one call."""
-        d = self.dims
         t = 0.0
         for _, _, adm in self.admits():
-            per_item = [counts.prefill_rows(a.prompt_len, a.start,
-                                            self.chunk) for a in adm]
+            per_item = [prefill_rows(a.prompt_len, a.start, self.chunk)
+                        for a in adm]
             for r in range(max((len(x) for x in per_item), default=0)):
                 rows = [x[r] for x in per_item if r < len(x)]
-                t += d.L * counts.roofline_seconds(
-                    *counts.paged_prefill_call(d, rows), self.peaks)
+                t += roofline_seconds(
+                    *self.counts.paged_prefill_step(rows), self.peaks)
         return t
 
     def idle_no_work_s(self) -> float:

@@ -4,6 +4,7 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import json
+import sys
 from pathlib import Path
 from types import ModuleType
 from typing import Any, Dict, List
@@ -52,10 +53,13 @@ def load_cell(name: str) -> Cell:
 
 def load_module(path: Path) -> ModuleType:
     """Import a file by path (metric readers are named after metrics,
-    which may hold a dot)."""
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_" + path.stem.replace(".", "_"), path)
+    which may hold a dot), once a process."""
+    name = f"chipbench_{path.parent.name}_{path.stem.replace('.', '_')}"
+    if name in sys.modules:
+        return sys.modules[name]
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
 

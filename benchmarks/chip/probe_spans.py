@@ -42,7 +42,7 @@ def main(argv=None) -> int:
         cell.config = rehearse_conf(cell.config)
     import jax
     devs = run_cell.devices_or_exit(cell, args.rehearse)
-    from harness import counts, peaks, readings, spans
+    from harness import peaks, readings, spans
     from harness import trace as trace_lib
     from harness.client import Client
     from repro.serving import Telemetry
@@ -75,7 +75,8 @@ def main(argv=None) -> int:
         rt_spans = spans.load(tdir)
         read_s = time.perf_counter() - t0
 
-    run = readings.Run(lg, counts.Dims.of(system.cfg), pk,
+    ref = spec.reference_module(cell.config)
+    run = readings.Run(lg, ref.counts_of(cell.config), pk,
                        rt.scfg.prefill_chunk, rt.scfg.decode_chunk,
                        opened["setup_s"])
     off = trace_lib.host_offset(tr_ev, lg.decodes[0].t0)

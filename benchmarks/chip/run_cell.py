@@ -177,7 +177,7 @@ def main(argv=None) -> int:
         import numpy as np
     with timer("devices"):
         devs = devices_or_exit(cell, args.rehearse)
-    from harness import check, counts, model, peaks, readings
+    from harness import check, model, peaks, readings
     from harness import trace as trace_lib
     pk = None if args.rehearse else peaks.peaks(devs[0].device_kind)
     system, tr, warm, cache_dir = setup(cell, args.seed, args.seconds,
@@ -190,7 +190,9 @@ def main(argv=None) -> int:
             args.rehearse)
         stats = devs[0].memory_stats() or {}
         peak = stats.get("peak_bytes_in_use")
-        run = readings.Run(lg, counts.Dims.of(system.cfg),
+        conf = cell.config
+        ref = spec.reference_module(conf)
+        run = readings.Run(lg, ref.counts_of(conf),
                            pk or {"bf16_flops_per_s": 1.0,
                                   "hbm_bytes_per_s": 1.0},
                            rt.scfg.prefill_chunk, rt.scfg.decode_chunk,
@@ -208,15 +210,13 @@ def main(argv=None) -> int:
 
     # the program's state goes before the reference runs; the weights
     # (the benchmark's own) stay
-    conf = cell.config
     rt.cache = None
     rt.params = None
     gc.collect()
     t0 = time.perf_counter()
-    ref = spec.reference_module(conf)
     min_tokens = check.min_tokens(args.rehearse)
     picked = check.sample(lg.recs, args.seed, min_tokens=min_tokens)
-    weights = check.reference_weights(system.backbone)
+    weights = ref.reference_weights(system.backbone)
     g = check.gaps(conf, ref, weights,
                    lambda a: system.adapter_fn(model.adapter_key(
                        args.seed, a)), picked)
